@@ -26,6 +26,8 @@ def run(rounds: int = 10) -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     r = 10
     if "--rounds" in sys.argv:
         r = int(sys.argv[sys.argv.index("--rounds") + 1])

@@ -88,10 +88,13 @@ import sys
 import time
 
 # --agg-scale shards the cohort reduction over a multi-device client
-# mesh; on a CPU host that means forced fake devices, and the flag only
-# takes effect if set before jax initializes (first import locks the
-# device count).
-if "--agg-scale" in sys.argv and "xla_force_host_platform_device_count" \
+# mesh. Run on the CPU (JAX_PLATFORMS=cpu) that means forced host
+# devices, and the flag only takes effect if set before jax initializes
+# (first import locks the device count); on an accelerator host the
+# mesh is the real devices.
+if "--agg-scale" in sys.argv \
+        and os.environ.get("JAX_PLATFORMS") == "cpu" \
+        and "xla_force_host_platform_device_count" \
         not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8")
@@ -114,6 +117,7 @@ from repro.models.resnet import ResNetConfig, init as rinit, loss_fn
 # shared with the tests' fixture and the engines' watchdogs
 from repro.obs.compile import compile_count  # noqa: E402
 from repro.obs.meta import run_meta  # noqa: E402
+from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def row(name: str, time_us=None, **metrics) -> dict:
@@ -891,6 +895,7 @@ def run_fleet(n_clients: int = 1_000_000, arrivals: int = 600,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=6)
     ap.add_argument("--samples", type=int, default=48)

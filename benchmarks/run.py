@@ -12,6 +12,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     train = "--train" in sys.argv
     rounds = 10
     if "--rounds" in sys.argv:

@@ -30,4 +30,6 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("\n".join(run()))

@@ -45,4 +45,6 @@ def run(train: bool = False, rounds: int = 12) -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("\n".join(run(train="--train" in sys.argv)))

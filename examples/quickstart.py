@@ -48,6 +48,7 @@ from repro.core.quant import QuantConfig
 from repro.data import SyntheticVision, lda_partition
 from repro.fl import ClientConfig, FLServer, ServerConfig
 from repro.models.resnet import ResNetConfig, init as resnet_init, loss_fn
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run_uniform(rounds: int, dp_noise=None):
@@ -220,6 +221,7 @@ def run_sparse(rounds: int, density: float):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=6)
     ap.add_argument("--hetero", action="store_true",

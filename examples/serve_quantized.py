@@ -21,9 +21,11 @@ from repro.core.lora import LoRAConfig
 from repro.core.quant import QuantConfig
 from repro.models import lm as LM
 from repro import serve
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = LM.LMConfig(name="edge-lm", n_layers=4, d_model=128, n_heads=4,
                       n_kv_heads=2, head_dim=32, d_ff=512, vocab=512,
                       lora=LoRAConfig(rank=8, alpha=128.0),

@@ -26,6 +26,7 @@ from repro.data.synthetic import markov_lm_batch
 from repro.models import lm as LM
 from repro.optim import sgd
 from repro.utils.tree import tree_size
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def make_cfg(full: bool) -> LM.LMConfig:
@@ -42,6 +43,7 @@ def make_cfg(full: bool) -> LM.LMConfig:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=4)
     ap.add_argument("--local-steps", type=int, default=8)

@@ -6,32 +6,30 @@ aggregated adapter tree, WITHOUT materializing K dequantized fp32 copies
 is 4-16x smaller than fp32 — this fusion is what makes the paper's
 quantization a server-side win too, not just a wire win).
 
-Like ``quant_pack``, the valid-column count is PER ROW: a (C, 1) int32
-sidecar masks each row's tail so a whole flat-tree message (every leaf's
-channel rows stacked into one ragged buffer, core/flat.py) aggregates a
-K-client cohort in ONE launch — contributions past a row's length are
-forced to exact zero, so flat rows slice apart cleanly.
+The valid-column count is PER ROW: an (C,) int32 vector masks each row's
+tail so a whole flat-tree message (every leaf's channel rows stacked into
+one ragged buffer, core/flat.py) aggregates a K-client cohort in ONE
+launch — contributions past a row's length are forced to exact zero, so
+flat rows slice apart cleanly.
 
-Two grid shapes over the same fold:
+Grid ``(C/bc, K/bk)`` with K innermost: each step folds a ``bk``-client
+tile into the fp32 output block resident in VMEM across the K walk, so
+the working set is bounded by ``bk`` and throughput is flat in K.
+``pick_block_k`` sizes ``bk`` from a VMEM budget; a cohort that fits
+rides in one tile.
 
-  * small cohorts — grid ``(C/bc,)``, the WHOLE K client dim rides in
-    the block (the packed payload is 4-16x smaller than fp32, so modest
-    K tiles fit VMEM);
-  * fleet cohorts — grid ``(C/bc, K/bk)`` with K innermost: each step
-    folds a ``bk``-client tile into the fp32 output block resident in
-    VMEM across the K walk (the ``_dequant_agg_kernel`` idiom), so the
-    working set is bounded by ``bk`` and throughput is flat in K.
-    ``pick_block_k`` sizes ``bk`` from a VMEM budget.
+Clients accumulate STRICTLY SEQUENTIALLY (k=0..K-1): fp addition is
+non-associative, so the walk is bit-identical to itself for EVERY
+``bk`` — tiling the cohort never changes the result.
 
-Both kernels accumulate clients STRICTLY SEQUENTIALLY (k=0..K-1): fp
-addition is non-associative, so the tiled walk is bit-identical to
-itself for EVERY ``bk`` — tiling the cohort never changes the result.
-Production calls always take the tiled program (one tile when the
-cohort fits); the whole-K kernel stays as the independently-shaped
-numerics oracle (``whole_k=True``), cross-checked at tolerance — the
-backend's FMA instruction selection differs ~1 ulp between the two
-program shapes, so cross-PROGRAM bit identity is not promised, only
-cross-``bk`` bit identity within the tiled program.
+Layout on the TPU: a word's ``per`` levels are lane-interleaved, and
+spreading them back out in the kernel is a shape cast Mosaic pads 32x
+(a minor dim of ``per``). So the kernel never interleaves: level ``i``
+of word ``j`` accumulates into output column ``i*Nw + j`` (whole
+lane-aligned ``(bc, Nw)`` slabs, one per level position), and the
+wrapper restores level order and masks the row tails in one XLA pass
+over the (C, N) result. Words ride as int32 (Mosaic has no uint32 <->
+f32 cast; the bitcast leaves the bits unchanged).
 """
 from __future__ import annotations
 
@@ -39,235 +37,104 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 Array = jax.Array
 
 # VMEM working-set budget for auto-picked client tiles (~half a v5e
-# core's 16 MiB VMEM, leaving room for double buffering)
+# core's 16 MiB scoped VMEM, leaving room for the compiler's own use)
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+
+# one (8, 128) fp32 VMEM tile: what each client's (bc, 1) sidecar and
+# (1, 1) weight block occupies once laid out
+_TILE_BYTES = 8 * 128 * 4
 
 
 def pick_block_k(k: int, nw: int, bits: int, block_c: int = 8,
                  vmem_bytes: int = VMEM_BUDGET_BYTES) -> int:
-    """Largest pow2 client tile whose per-step working set — the packed
-    ``(bk, bc, Nw)`` tile plus the fp32 unpack/contribution intermediates
-    and the resident ``(bc, N)`` output block — fits the VMEM budget."""
-    per = 32 // bits
-    n = nw * per
-    per_client = block_c * (nw * 4 + 2 * n * 4)
-    out_bytes = block_c * n * 4
-    bk = max(1, (vmem_bytes - out_bytes) // max(per_client, 1))
+    """Largest pow2 client tile whose double-buffered inputs — the
+    packed ``(bk, bc, Nw)`` words plus the scale/zp/weight blocks, one
+    VMEM tile each per client — and the double-buffered ``(bc, N)``
+    output block fit the VMEM budget."""
+    n = nw * (32 // bits)
+    per_client = 2 * (block_c * nw * 4 + 3 * _TILE_BYTES)
+    out_bytes = 2 * block_c * n * 4
+    bk = max(1, (vmem_bytes - out_bytes) // per_client)
     bk = 1 << (int(bk).bit_length() - 1)
     return int(min(bk, max(int(k), 1)))
 
 
-def _seq_fold(acc, words, scale, zp, w, bits: int):
-    """Fold a (kb, bc, Nw) packed tile into the (bc, N) accumulator,
-    one client at a time in index order (see module docstring: the
-    sequential order is the bit-parity contract across tile sizes)."""
+def _dequant_agg_rows_kernel(packed_ref, scale_ref, zp_ref, w_ref, out_ref,
+                             *, bits: int):
+    """Grid (C/bc, K/bk), K innermost. The fp32 output block stays
+    resident in VMEM across the K walk; each step folds a bk-client
+    tile into it, one client at a time in index order (the bit-parity
+    contract across tile sizes)."""
     per = 32 // bits
-    shifts = (jax.lax.broadcasted_iota(
-        jnp.uint32, (*words.shape, per), 3) * jnp.uint32(bits))
-    msk = jnp.uint32((1 << bits) - 1)
-    lv = ((words[..., None] >> shifts) & msk).astype(jnp.float32)
-    lv = lv.reshape(*words.shape[:2], words.shape[2] * per)  # (kb, bc, N)
-    contrib = w[..., None] * ((lv - zp) * scale)   # sidecars (kb, bc, 1)
-    for i in range(words.shape[0]):
-        acc = acc + contrib[i]
-    return acc
+    nw = packed_ref.shape[2]
+    mask = (1 << bits) - 1
 
-
-def _dequant_agg_kernel(packed_ref, scale_ref, zp_ref, w_ref, nv_ref,
-                        out_ref, *, bits: int):
-    k = pl.program_id(1)
-    per = 32 // bits
-    words = packed_ref[0]                                  # (bc, Nw) uint32
-    shifts = (jax.lax.broadcasted_iota(
-        jnp.uint32, (*words.shape, per), 2) * jnp.uint32(bits))
-    mask = jnp.uint32((1 << bits) - 1)
-    lv = ((words[..., None] >> shifts) & mask).astype(jnp.float32)
-    lv = lv.reshape(words.shape[0], words.shape[1] * per)  # (bc, N)
-    scale = scale_ref[0]                                   # (bc, 1)
-    zp = zp_ref[0]
-    w = w_ref[0, 0]
-    nv = nv_ref[...]                                       # (bc, 1) int32
-    col = jax.lax.broadcasted_iota(jnp.int32, lv.shape, 1)
-    contrib = jnp.where(col < nv, w * (lv - zp) * scale, 0.0)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = contrib
-
-    @pl.when(k > 0)
-    def _acc():
-        out_ref[...] += contrib
-
-
-def _dequant_agg_rows_kernel(packed_ref, scale_ref, zp_ref, w_ref, nv_ref,
-                             out_ref, *, bits: int):
-    """Flat-tree small-cohort variant: the WHOLE K client dim rides in
-    the block and the grid walks channel blocks only — one launch, one
-    output pass. The bit-parity oracle for the K-tiled walk below."""
-    acc = _seq_fold(jnp.zeros(out_ref.shape, jnp.float32),
-                    packed_ref[...], scale_ref[...], zp_ref[...],
-                    w_ref[...], bits)
-    nv = nv_ref[...]                                 # (bc, 1) int32
-    col = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
-    out_ref[...] = jnp.where(col < nv, acc, 0.0)
-
-
-def _dequant_agg_rows_ktiled_kernel(packed_ref, scale_ref, zp_ref, w_ref,
-                                    nv_ref, out_ref, *, bits: int):
-    """Fleet-cohort variant: grid (C/bc, K/bk), K innermost. The fp32
-    output block stays resident in VMEM across the K walk; each step
-    folds a bk-client tile into it. Row tails accumulate the same
-    garbage as the whole-K kernel and are masked once on the last tile,
-    so the result is bit-identical to ``_dequant_agg_rows_kernel``."""
-    kt = pl.program_id(1)
-    nt = pl.num_programs(1)
-
-    @pl.when(kt == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    acc = _seq_fold(out_ref[...], packed_ref[...], scale_ref[...],
-                    zp_ref[...], w_ref[...], bits)
+    def client(kk, carry):
+        words = packed_ref[kk]                             # (bc, Nw) int32
+        scale, zp, w = scale_ref[kk], zp_ref[kk], w_ref[kk]
+        for i in range(per):
+            lv = ((words >> (bits * i)) & mask).astype(jnp.float32)
+            cols = slice(i * nw, (i + 1) * nw)
+            out_ref[:, cols] = out_ref[:, cols] + w * ((lv - zp) * scale)
+        return carry
 
-    @pl.when(kt < nt - 1)
-    def _carry():
-        out_ref[...] = acc
-
-    @pl.when(kt == nt - 1)
-    def _final():
-        nv = nv_ref[...]
-        col = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
-        out_ref[...] = jnp.where(col < nv, acc, 0.0)
-
-
-def _pad_rows(packed, scale, zp, n_valid, c_pad: int):
-    """Transparent C-padding: zero word rows with n_valid=0 (and scale 0)
-    aggregate to exact zero and are sliced off by the caller."""
-    packed = jnp.pad(packed, ((0, 0), (0, c_pad), (0, 0)))
-    scale = jnp.pad(scale, ((0, 0), (0, c_pad)))
-    zp = jnp.pad(zp, ((0, 0), (0, c_pad)))
-    n_valid = jnp.pad(n_valid, (0, c_pad))
-    return packed, scale, zp, n_valid
+    jax.lax.fori_loop(0, packed_ref.shape[0], client, 0)
 
 
 def dequant_agg_rows_pallas(packed: Array, scale: Array, zp: Array,
                             weights: Array, n_valid: Array, bits: int, *,
                             block_c: int = 8,
                             block_k: int | None = None,
-                            whole_k: bool = False,
                             interpret: bool = False) -> Array:
     """packed (K, C, Nw) uint32; scale/zp (K, C); weights (K,);
     n_valid (C,) per-row true lengths. One launch aggregates the whole
-    flat-tree cohort; tails past each row's length are exact zeros.
-    Arbitrary C is padded transparently to ``block_c``. ``block_k``
-    (default: VMEM-budget auto-pick) sizes the K tile; small cohorts
-    ride in ONE tile (grid (C/bc, 1) — the whole-K fast path, identical
-    work to the single-pass oracle kernel). ``whole_k=True`` forces the
-    original whole-K kernel program — the numerics oracle the tiled
-    walk is cross-checked against in tests (tolerance-level: backend
-    FMA instruction selection differs ~1 ulp between the two program
-    shapes; the tiled kernel itself is bit-identical across every
-    ``bk``). Returns (C, N) fp32."""
+    flat-tree cohort; tails past each row's length are exact zeros. C
+    need not divide ``block_c``. ``block_k`` (default: VMEM-budget
+    auto-pick) sizes the K tile; small cohorts ride in ONE tile (grid
+    (C/bc, 1)). The result is bit-identical for every ``block_k``.
+    Returns (C, N) fp32."""
     k, c, nw = packed.shape
     per = 32 // bits
     n = nw * per
     nv = jnp.asarray(n_valid, jnp.int32).reshape(c)
-    c_pad = (-c) % block_c
-    if c_pad:
-        packed, scale, zp, nv = _pad_rows(packed, scale, zp, nv, c_pad)
-    cq = c + c_pad
-    nv = nv.reshape(cq, 1)
     bk = pick_block_k(k, nw, bits, block_c) if block_k is None \
         else int(block_k)
-    if whole_k:
-        out = pl.pallas_call(
-            functools.partial(_dequant_agg_rows_kernel, bits=bits),
-            grid=(cq // block_c,),
-            in_specs=[
-                pl.BlockSpec((k, block_c, nw), lambda i: (0, i, 0)),
-                pl.BlockSpec((k, block_c, 1), lambda i: (0, i, 0)),
-                pl.BlockSpec((k, block_c, 1), lambda i: (0, i, 0)),
-                pl.BlockSpec((k, 1), lambda i: (0, 0)),
-                pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((block_c, n), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((cq, n), jnp.float32),
-            interpret=interpret,
-        )(packed, scale[..., None], zp[..., None], weights[:, None], nv)
-        return out[:c]
     bk = min(bk, k)
+    weights = weights.astype(jnp.float32)
     k_pad = (-k) % bk
     if k_pad:
         # zero-weight phantom clients (scale 0 -> contribution exactly
-        # +0.0) appended AFTER the real fold sequence: bit parity holds
+        # +0.0) go AFTER the real fold sequence, so bit parity holds
         packed = jnp.pad(packed, ((0, k_pad), (0, 0), (0, 0)))
         scale = jnp.pad(scale, ((0, k_pad), (0, 0)))
         zp = jnp.pad(zp, ((0, k_pad), (0, 0)))
         weights = jnp.pad(weights, (0, k_pad))
-    kq = k + k_pad
+    # a C that block_c does not divide ends in a partial row block: its
+    # out-of-range rows read undefined values and are never written back
     out = pl.pallas_call(
-        functools.partial(_dequant_agg_rows_ktiled_kernel, bits=bits),
-        grid=(cq // block_c, kq // bk),          # K innermost: the out
-        in_specs=[                               # block accumulates
+        functools.partial(_dequant_agg_rows_kernel, bits=bits),
+        grid=(pl.cdiv(c, block_c), (k + k_pad) // bk),   # K innermost:
+        in_specs=[                          # the out block accumulates
             pl.BlockSpec((bk, block_c, nw), lambda i, t: (t, i, 0)),
             pl.BlockSpec((bk, block_c, 1), lambda i, t: (t, i, 0)),
             pl.BlockSpec((bk, block_c, 1), lambda i, t: (t, i, 0)),
-            pl.BlockSpec((bk, 1), lambda i, t: (t, 0)),
-            pl.BlockSpec((block_c, 1), lambda i, t: (i, 0)),
+            pl.BlockSpec((bk, 1, 1), lambda i, t: (t, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_c, n), lambda i, t: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cq, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((c, n), jnp.float32),
         interpret=interpret,
-    )(packed, scale[..., None], zp[..., None], weights[:, None], nv)
-    return out[:c]
-
-
-def dequant_agg_pallas(packed: Array, scale: Array, zp: Array,
-                       weights: Array, bits: int, *,
-                       n_valid: int | Array | None = None,
-                       block_c: int = 8,
-                       interpret: bool = False) -> Array:
-    """packed (K, C, Nw) uint32; scale/zp (K, C); weights (K,).
-
-    ``n_valid`` (scalar or (C,) vector, default N) zeroes each row's
-    tail past its true length — shared by all K clients, since the row
-    layout is a property of the message structure, not the sender.
-    Arbitrary C is padded transparently to ``block_c``.
-
-    Returns (C, N) fp32 weighted sum of dequantized messages."""
-    k, c, nw = packed.shape
-    per = 32 // bits
-    n = nw * per
-    if n_valid is None:
-        n_valid = n
-    if isinstance(n_valid, (int, np.integer)):
-        nv = jnp.full((c,), n_valid, jnp.int32)
-    else:
-        nv = jnp.asarray(n_valid, jnp.int32).reshape(c)
-    c_pad = (-c) % block_c
-    if c_pad:
-        packed, scale, zp, nv = _pad_rows(packed, scale, zp, nv, c_pad)
-    cq = c + c_pad
-    nv = nv.reshape(cq, 1)
-    grid = (cq // block_c, k)
-    out = pl.pallas_call(
-        functools.partial(_dequant_agg_kernel, bits=bits),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_c, nw), lambda i, kk: (kk, i, 0)),
-            pl.BlockSpec((1, block_c, 1), lambda i, kk: (kk, i, 0)),
-            pl.BlockSpec((1, block_c, 1), lambda i, kk: (kk, i, 0)),
-            pl.BlockSpec((1, 1), lambda i, kk: (kk, 0)),
-            pl.BlockSpec((block_c, 1), lambda i, kk: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_c, n), lambda i, kk: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cq, n), jnp.float32),
-        interpret=interpret,
-    )(packed, scale[..., None], zp[..., None], weights[:, None], nv)
-    return out[:c]
+    )(jax.lax.bitcast_convert_type(packed, jnp.int32), scale[..., None],
+      zp[..., None], weights[:, None, None])
+    # column i*Nw + j holds level i of word j: back to level order
+    out = out.reshape(c, per, nw).swapaxes(1, 2).reshape(c, n)
+    col = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+    return jnp.where(col < nv[:, None], out, 0.0)

@@ -44,6 +44,10 @@ from jax.experimental import pallas as pl
 
 Array = jax.Array
 
+# fp32 operands contract at fp32 on the MXU (Mosaic's default precision
+# for a dot may round them to bf16); the jnp twins pass the same
+F32 = jax.lax.Precision.HIGHEST
+
 
 def _lora_matmul_kernel(x_ref, w_ref, a_ref, b_ref, out_ref, *, s: float):
     kk = pl.program_id(2)
@@ -101,18 +105,28 @@ def _gather_rows(ref, ids_ref, bm: int):
         [ref[pl.ds(ids_ref[m, 0], 1)] for m in range(bm)], axis=0)
 
 
+def row_matmul(x: Array, m: Array, contract: int = 1) -> Array:
+    """Per-row matmul of x (bm, K) with m[i] (bm, K, N) contracted on
+    dim ``contract`` (2 for a (bm, N, K) rhs), fp32 accumulate. The lhs
+    rides as (bm, 1, K): Mosaic lowers a batched dot only when both
+    operands carry the batch dim."""
+    y = jax.lax.dot_general(x[:, None, :], m,
+                            (((2,), (contract,)), ((0,), (0,))),
+                            precision=F32,
+                            preferred_element_type=jnp.float32)
+    return y[:, 0, :]
+
+
 def _multi_lora_matmul_kernel(ids_ref, x_ref, w_ref, a_ref, b_ref,
                               out_ref, *, s: float):
     x = x_ref[...]                                        # (bm, K)
-    acc = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+    acc = jnp.dot(x, w_ref[...], precision=F32,
+                  preferred_element_type=jnp.float32)
     bm = x.shape[0]
     am = _gather_rows(a_ref, ids_ref, bm)                 # (bm, K, R)
     bmat = _gather_rows(b_ref, ids_ref, bm)               # (bm, R, bn)
-    h = jax.lax.dot_general(x, am, (((1,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(h.astype(bmat.dtype), bmat,
-                            (((1,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
+    h = row_matmul(x, am)
+    y = row_matmul(h.astype(bmat.dtype), bmat)
     out_ref[...] = acc + s * y
 
 
@@ -146,21 +160,23 @@ def multi_lora_matmul_pallas(x: Array, w: Array, a_stack: Array,
 
 
 def _unpack_block(words: Array, bits: int):
-    """(..., Nw) uint32 -> (..., Nw*per) fp32 levels, little-endian
-    (broadcasted-iota shifts — the TPU-safe twin of ref.unpack_words)."""
+    """(..., Nw) int32 words -> (..., Nw*per) fp32 levels, little-endian
+    (broadcasted-iota shifts — the in-kernel twin of ref.unpack_words).
+    Words ride as int32: Mosaic has no uint32 <-> f32 cast, and the
+    mask drops the sign bits an arithmetic shift brings in."""
     per = 32 // bits
-    shifts = (jax.lax.broadcasted_iota(
-        jnp.uint32, (*words.shape, per), words.ndim) * jnp.uint32(bits))
-    msk = jnp.uint32((1 << bits) - 1)
-    lv = ((words[..., None] >> shifts) & msk).astype(jnp.float32)
-    return lv.reshape(*words.shape[:-1], words.shape[-1] * per)
+    shifts = jax.lax.broadcasted_iota(
+        jnp.int32, (*words.shape, per), words.ndim) * bits
+    lv = ((words[..., None] >> shifts) & ((1 << bits) - 1))
+    return lv.astype(jnp.float32).reshape(
+        *words.shape[:-1], words.shape[-1] * per)
 
 
 def _multi_lora_matmul_q_kernel(ids_ref, x_ref, w_ref, aq_ref, as_ref,
                                 az_ref, bq_ref, bs_ref, bz_ref, out_ref,
                                 *, s: float, bits: int, k: int, r: int):
     x = x_ref[...].astype(jnp.float32)                    # (bm, K)
-    acc = jnp.dot(x, w_ref[...].astype(jnp.float32),
+    acc = jnp.dot(x, w_ref[...].astype(jnp.float32), precision=F32,
                   preferred_element_type=jnp.float32)
     bm = x.shape[0]
     aw = _gather_rows(aq_ref, ids_ref, bm)                # (bm, R, KW)
@@ -175,10 +191,8 @@ def _multi_lora_matmul_q_kernel(ids_ref, x_ref, w_ref, aq_ref, as_ref,
         * asc[..., None]                                  # (bm, R, K)
     bdeq = (_unpack_block(bw, bits)[..., :r] - bzp[..., None]) \
         * bsc[..., None]                                  # (bm, bn, R)
-    h = jax.lax.dot_general(x, adeq, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(h, bdeq, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
+    h = row_matmul(x, adeq, contract=2)
+    y = row_matmul(h, bdeq, contract=2)
     out_ref[...] = acc + s * y
 
 
@@ -224,6 +238,7 @@ def multi_lora_matmul_q_pallas(x: Array, w: Array, aq: Array, a_scale: Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(ids.reshape(m, 1).astype(jnp.int32), x, w, aq, a_scale, a_zp,
-      bq, b_scale, b_zp)
+    )(ids.reshape(m, 1).astype(jnp.int32), x, w,
+      jax.lax.bitcast_convert_type(aq, jnp.int32), a_scale, a_zp,
+      jax.lax.bitcast_convert_type(bq, jnp.int32), b_scale, b_zp)
     return out.astype(x.dtype)

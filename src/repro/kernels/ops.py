@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 Pad-to-alignment, channel-first reshaping from arbitrary tensors, and
-backend dispatch: on TPU the kernels compile natively; on CPU (this
-container) they run in interpret mode — same kernel body, Python
-execution, used by the test-suite oracles.
+backend dispatch: on TPU the kernels compile natively (Mosaic); on any
+other backend they run in interpret mode — same kernel body, Python
+execution — or lower to their bit-identical jnp twins inside the same
+jitted program (``quant_pack_rows``, ``dequant_agg_rows``, the
+multi-adapter matmuls), which the test-suite checks against the kernels.
 """
 from __future__ import annotations
 
@@ -16,10 +18,9 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ref
-from repro.kernels.dequant_agg import dequant_agg_pallas, \
-    dequant_agg_rows_pallas, pick_block_k
+from repro.kernels.dequant_agg import dequant_agg_rows_pallas, pick_block_k
 from repro.kernels.lora_matmul import lora_matmul_pallas, \
-    multi_lora_matmul_pallas, multi_lora_matmul_q_pallas
+    multi_lora_matmul_pallas, multi_lora_matmul_q_pallas, row_matmul, F32
 from repro.kernels.quant_pack import quant_pack_pallas
 
 Array = jax.Array
@@ -155,13 +156,11 @@ CLIENT_AXIS = "clients"
 @functools.lru_cache(maxsize=None)
 def _sharded_agg_fn(mesh: Mesh, axis: str, bits: int, block_c: int,
                     block_k: int | None):
-    from jax.experimental.shard_map import shard_map
-
     spec = P(axis)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(spec, spec, spec, spec, P()), out_specs=P(),
-             check_rep=False)
+             check_vma=False)
     def _local(p, s, z, w, nv):
         part = dequant_agg_rows(p, s, z, w, nv, bits, block_c=block_c,
                                 block_k=block_k)
@@ -197,14 +196,17 @@ def dequant_agg_rows_sharded(packed: Array, scale: Array, zp: Array,
 def dequant_agg(packed: Array, scale: Array, zp: Array, weights: Array,
                 bits: int, block_c: int = 8,
                 n_valid: Array | None = None) -> Array:
-    """``n_valid`` (optional (C,) vector) masks each row's tail to exact
-    zero — the flat-tree codec aggregates every leaf of a K-client
-    cohort in one launch and slices the rows apart afterwards."""
-    nvp = None if n_valid is None else jnp.asarray(n_valid, jnp.int32)
-    return dequant_agg_pallas(packed, scale,
-                              jnp.where(scale > 0, zp, 0.0), weights,
-                              bits, n_valid=nvp, block_c=block_c,
-                              interpret=_interpret())
+    """Per-leaf cohort aggregate: packed (K, C, Nw), sidecars (K, C) ->
+    (C, N) fp32 through the same kernel as :func:`dequant_agg_rows`.
+    ``n_valid`` (optional (C,) vector) masks each row's tail to exact
+    zero."""
+    _, c, nw = packed.shape
+    nv = jnp.full((c,), nw * (32 // bits), jnp.int32) if n_valid is None \
+        else jnp.asarray(n_valid, jnp.int32)
+    return dequant_agg_rows_pallas(packed, scale,
+                                   jnp.where(scale > 0, zp, 0.0), weights,
+                                   nv, bits, block_c=block_c,
+                                   interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("s",))
@@ -241,15 +243,12 @@ def _blk(dim: int, target: int) -> int:
 def _multi_lora_matmul_jnp(x: Array, w: Array, a_stack: Array,
                            b_stack: Array, ids: Array, s: float) -> Array:
     """Bit-identical jnp twin of the multi-adapter kernel (same gather
-    semantics, same batched dot_generals, fp32 accumulation)."""
-    acc = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    semantics, same per-row dots, fp32 accumulation)."""
+    acc = jnp.dot(x, w, precision=F32, preferred_element_type=jnp.float32)
     am = jnp.take(a_stack, ids, axis=0)                   # (M, K, R)
     bm = jnp.take(b_stack, ids, axis=0)                   # (M, R, N)
-    h = jax.lax.dot_general(x, am, (((1,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(h.astype(bm.dtype), bm,
-                            (((1,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
+    h = row_matmul(x, am)
+    y = row_matmul(h.astype(bm.dtype), bm)
     return (acc + s * y).astype(x.dtype)
 
 
@@ -266,18 +265,12 @@ def multi_lora_matmul(x: Array, w: Array, a_stack: Array, b_stack: Array,
     ids = jnp.asarray(ids, jnp.int32)
     if _interpret():
         return _multi_lora_matmul_jnp(x, w, a_stack, b_stack, ids, s)
-    m, k = x.shape
+    m = x.shape[0]
     n = w.shape[1]
-    r = a_stack.shape[2]
-    rp = max(128, ((r + 127) // 128) * 128)
-    ap = _pad_to(a_stack, rp, 2)
-    bp = _pad_to(b_stack, rp, 1)
-    mp = -(-m // 8) * 8
-    xp = _pad_to(x, 8, 0)
-    idp = _pad_to(ids, 8, 0)
-    out = multi_lora_matmul_pallas(xp, w, ap, bp, idp, s,
-                                   block_m=8, block_n=_blk(n, 256))
-    return out[:m] if mp != m else out
+    out = multi_lora_matmul_pallas(_pad_to(x, 8, 0), w, a_stack, b_stack,
+                                   _pad_to(ids, 8, 0), s, block_m=8,
+                                   block_n=_blk(n, 256))
+    return out[:m]
 
 
 def _multi_lora_matmul_q_jnp(x: Array, w: Array, aq: Array, a_scale: Array,
@@ -290,7 +283,7 @@ def _multi_lora_matmul_q_jnp(x: Array, w: Array, aq: Array, a_scale: Array,
     k = x.shape[1]
     r = a_scale.shape[1]
     xf = x.astype(jnp.float32)
-    acc = jnp.dot(xf, w.astype(jnp.float32),
+    acc = jnp.dot(xf, w.astype(jnp.float32), precision=F32,
                   preferred_element_type=jnp.float32)
     aw = jnp.take(aq, ids, axis=0)                        # (M, R, KW)
     asc = jnp.take(a_scale, ids, axis=0)
@@ -302,10 +295,8 @@ def _multi_lora_matmul_q_jnp(x: Array, w: Array, aq: Array, a_scale: Array,
     adeq = (la - azp[..., None]) * asc[..., None]         # (M, R, K)
     lb = ref.unpack_words(bw, bits)[..., :r].astype(jnp.float32)
     bdeq = (lb - bzp[..., None]) * bsc[..., None]         # (M, N, R)
-    h = jax.lax.dot_general(xf, adeq, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(h, bdeq, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
+    h = row_matmul(xf, adeq, contract=2)
+    y = row_matmul(h, bdeq, contract=2)
     return (acc + s * y).astype(x.dtype)
 
 
@@ -333,7 +324,7 @@ def multi_lora_matmul_packed(x: Array, w: Array, aq: Array, a_scale: Array,
     out = multi_lora_matmul_q_pallas(xp, w, aq, a_scale, a_zp, bq,
                                      b_scale, b_zp, idp, s, bits,
                                      block_m=8, block_n=_blk(n, 256))
-    return out[:m] if out.shape[0] != m else out
+    return out[:m]
 
 
 # ---------------------------------------------------------------------------

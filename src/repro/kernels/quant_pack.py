@@ -14,8 +14,18 @@ as one ragged (C_total, N_max) buffer in a single launch, each row
 masked to its own leaf's true length.
 
 Tiling: grid over channel blocks; each step holds an (BC, N) fp32 tile
-plus its (BC, N/per) uint32 output in VMEM. BC=8 sublanes; N padded to a
+plus its (BC, N/per) word output in VMEM. BC=8 sublanes; N padded to a
 multiple of 128*per by the wrapper (ops.py) so lanes stay aligned.
+
+Packing on the TPU: gathering every ``per``-th lane into one word is a
+lane-strided shape cast Mosaic refuses, so each 128-word output block is
+an exact MXU product instead. The block's ``per*128`` levels (integers
+<= 255, exact in bf16) multiply a 0/2^(bits*i) selection matrix in two
+halves of ``per/2`` levels each, so every f32 accumulator holds at most
+16 bits (exact); the halves combine in int32 as ``hi << 16 | lo``. The
+kernel emits int32 words (Mosaic has no f32 <-> uint32 cast) and the
+wrapper bitcasts them to the uint32 wire words: the little-endian
+layout of ``ref.pack_words``, bit for bit.
 """
 from __future__ import annotations
 
@@ -29,8 +39,24 @@ from jax.experimental import pallas as pl
 Array = jax.Array
 
 
-def _quant_pack_kernel(x_ref, nv_ref, packed_ref, scale_ref, zp_ref, *,
-                       bits: int):
+def _pack_selectors(bits: int) -> np.ndarray:
+    """(2, per*128, 128) bf16-exact selection matrices: half ``h`` maps
+    level ``i`` of word ``j`` (lane ``j*per + i`` of a block) to output
+    lane ``j`` with weight ``2^(bits * (i - h*per/2))``."""
+    per = 32 // bits
+    half = per // 2
+    row = np.arange(per * 128)[:, None]
+    col = np.arange(128)[None, :]
+    j, i = row // per, row % per
+    out = np.zeros((2, per * 128, 128), np.float32)
+    for h in range(2):
+        hit = (j == col) & (i // half == h)
+        out[h] = np.where(hit, 2.0 ** (bits * (i % half)), 0.0)
+    return out
+
+
+def _quant_pack_kernel(x_ref, nv_ref, sel_ref, packed_ref, scale_ref,
+                       zp_ref, *, bits: int):
     x = x_ref[...].astype(jnp.float32)                    # (bc, N)
     n = x.shape[1]
     qmax = (1 << bits) - 1
@@ -53,13 +79,15 @@ def _quant_pack_kernel(x_ref, nv_ref, packed_ref, scale_ref, zp_ref, *,
     q = jnp.round(x / scale[:, None]) + zp[:, None]
     # canonical zero padding past each row's n_valid: packed words are
     # byte-identical to the host/wire re-packing paths (messages/flat)
-    q = jnp.where(valid, jnp.clip(q, 0, qmax), 0)
-    q = q.astype(jnp.uint32)
-    # pack `per` levels into each uint32 word (little-endian)
-    grp = q.reshape(q.shape[0], n // per, per)
-    shifts = (jax.lax.broadcasted_iota(jnp.uint32, grp.shape, 2)
-              * jnp.uint32(bits))
-    packed_ref[...] = jnp.sum(grp << shifts, axis=-1).astype(jnp.uint32)
+    q = jnp.where(valid, jnp.clip(q, 0, qmax), 0).astype(jnp.bfloat16)
+    lo_sel, hi_sel = sel_ref[0], sel_ref[1]
+    blk = per * 128
+    for b in range(n // blk):               # one 128-word block at a time
+        lv = q[:, b * blk:(b + 1) * blk]
+        lo = jnp.dot(lv, lo_sel, preferred_element_type=jnp.float32)
+        hi = jnp.dot(lv, hi_sel, preferred_element_type=jnp.float32)
+        packed_ref[:, b * 128:(b + 1) * 128] = \
+            (hi.astype(jnp.int32) << 16) | lo.astype(jnp.int32)
     scale_ref[...] = scale[:, None]
     zp_ref[...] = zp[:, None]
 
@@ -78,7 +106,7 @@ def quant_pack_pallas(x: Array, bits: int, *,
     Returns (packed (C, N*bits/32) uint32, scale (C,), zp (C,))."""
     c, n = x.shape
     per = 32 // bits
-    assert c % block_c == 0 and n % per == 0
+    assert c % block_c == 0 and n % (per * 128) == 0
     if n_valid is None:
         n_valid = n
     if isinstance(n_valid, (int, np.integer)):
@@ -94,6 +122,7 @@ def quant_pack_pallas(x: Array, bits: int, *,
         in_specs=[
             pl.BlockSpec((block_c, n), lambda i: (i, 0)),
             pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
+            pl.BlockSpec((2, per * 128, 128), lambda i: (0, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_c, nw), lambda i: (i, 0)),
@@ -101,10 +130,11 @@ def quant_pack_pallas(x: Array, bits: int, *,
             pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((c, nw), jnp.uint32),
+            jax.ShapeDtypeStruct((c, nw), jnp.int32),
             jax.ShapeDtypeStruct((c, 1), jnp.float32),
             jax.ShapeDtypeStruct((c, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, nv)
+    )(x, nv, jnp.asarray(_pack_selectors(bits), jnp.bfloat16))
+    packed = jax.lax.bitcast_convert_type(packed, jnp.uint32)
     return packed, scale[:, 0], zp[:, 0]

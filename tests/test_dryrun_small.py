@@ -79,7 +79,7 @@ FL_ROUND_SCRIPT = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_dryrun_cells_small_mesh():
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=1200)
     assert "ALL_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-2000:]
@@ -87,7 +87,7 @@ def test_dryrun_cells_small_mesh():
 
 @pytest.mark.slow
 def test_fl_round_multi_pod_compression():
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", FL_ROUND_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=1200)
     assert "ALL_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-2000:]
