@@ -363,8 +363,22 @@ class FLServer:
             self.rng.bit_generator.state = st
         return True
 
+    # -- tracing -------------------------------------------------------------
+    def _span(self, name: str, **args):
+        return self.tracer.span(name, track="fl/round", **args)
+
+    def _settle(self, tree: Any) -> None:
+        """With tracing on, wait for ``tree`` so that the open span holds
+        its device time; with tracing off the round does not block."""
+        if self.tracer.enabled:
+            jax.block_until_ready(tree)
+
     # -- one round (paper Fig. 1) --------------------------------------------
     def run_round(self) -> dict:
+        with self._span("fl/round", round=self.round):
+            return self._run_round()
+
+    def _run_round(self) -> dict:
         scfg, fcfg = self.scfg, self.fcfg
         rnd = self.round                      # schedules are 0-based
         k_target = scfg.clients_per_round
@@ -374,7 +388,6 @@ class FLServer:
         rank_of = {int(cid): self._rank_for(int(cid), rnd)
                    for cid in sampled}
         density = fcfg.uplink_density(rnd)
-        self.registry.inc("fl.rounds")
         # (1) broadcast precedes failure: downlink bytes are spent for
         # every dispatched client, at that client's rank
         down_bytes = 0
@@ -385,9 +398,6 @@ class FLServer:
 
         survivors = [cid for cid in (int(c) for c in sampled)
                      if not self._client_failed(rnd, cid)]
-        self.registry.inc("fl.clients_dropped",
-                          k_dispatch - len(survivors))
-        self.registry.observe("fl.cohort_size", len(survivors))
         # a dropped client's downlink was spent for nothing
         wasted_bytes = 0
         for cid in sampled:
@@ -443,46 +453,53 @@ class FLServer:
         results = []
         for r in sorted(buckets):
             cids = buckets[r]
-            with self.tracer.span("fl/broadcast", track="fl/round",
-                                  round=rnd, rank=r, clients=len(cids)):
+            with self._span("fl/broadcast", round=rnd, rank=r,
+                            clients=len(cids)):
                 g_bcast = flocora.broadcast(self.global_train, fcfg,
                                             rank=self._bcast_rank(r))
                 datas = [self.client_data[cid] for cid in cids]
-                batches, n_steps = stack_cohort_batches(
-                    self.rng, datas, self.ccfg,
-                    steps=self.cohort_schedule_steps)
-                if self.rank_schedule is not None:
-                    # pow2-padded buckets bound compile count for mixed
-                    # fleets; uniform fleets keep the exact-K classic
-                    # shape
-                    batches, n_steps = pad_cohort_batches(
-                        batches, n_steps, pow2_pad(len(cids)))
-                batches = jax.tree.map(jnp.asarray, batches)
-            with self.tracer.span("fl/client_train", track="fl/round",
-                                  round=rnd, rank=r, clients=len(cids)):
+                with self._span("fl/stage_batches", round=rnd, rank=r):
+                    batches, n_steps = stack_cohort_batches(
+                        self.rng, datas, self.ccfg,
+                        steps=self.cohort_schedule_steps)
+                    if self.rank_schedule is not None:
+                        # pow2-padded buckets bound compile count for
+                        # mixed fleets; uniform fleets keep the exact-K
+                        # classic shape
+                        batches, n_steps = pad_cohort_batches(
+                            batches, n_steps, pow2_pad(len(cids)))
+                with self._span("fl/h2d", round=rnd, rank=r):
+                    batches = jax.tree.map(jnp.asarray, batches)
+                    self._settle(batches)
+            with self._span("fl/client_train", round=rnd, rank=r,
+                            clients=len(cids)):
                 trained, losses = self.trainer(self.frozen, g_bcast,
                                                batches,
                                                jnp.asarray(n_steps))
-                losses = np.asarray(losses)
-            with self.tracer.span("fl/pack", track="fl/round",
-                                  round=rnd, rank=r, clients=len(cids)):
+                with self._span("fl/train_wait", round=rnd, rank=r):
+                    losses = np.asarray(losses)
+                    # no trainer time may leak into fl/pack
+                    self._settle(trained)
+            with self._span("fl/pack", round=rnd, rank=r,
+                            clients=len(cids)):
                 for k, cid in enumerate(cids):
-                    t_k = jax.tree.map(lambda x: x[k], trained)
+                    with self._span("fl/slice", client=cid):
+                        t_k = jax.tree.map(lambda x: x[k], trained)
                     res = self.aggregator.residual(cid, t_k) \
                         if ef else None
                     # start/dp_key engage only when fcfg.dp is set: the
                     # client's DELTA vs its broadcast is clipped+noised
                     # (keyed (round, cid)) before quantization
-                    msg, res = flocora.client_uplink(
-                        t_k, fcfg, res, rnd=rnd, start=g_bcast,
-                        dp_key=(rnd, cid), dp_seed=self.scfg.seed)
+                    with self._span("fl/encode", client=cid):
+                        msg, res = flocora.client_uplink(
+                            t_k, fcfg, res, rnd=rnd, start=g_bcast,
+                            dp_key=(rnd, cid), dp_seed=self.scfg.seed)
                     n_i = len(next(iter(datas[k].values())))
                     results.append((latency[cid], n_i, msg,
                                     float(losses[k]), r, cid, res))
 
         # every survivor transmitted its uplink (stragglers included)
-        with self.tracer.span("fl/uplink", track="fl/round", round=rnd,
-                              clients=len(results)):
+        with self._span("fl/uplink", round=rnd, clients=len(results)):
             up_bytes = 0
             for r_i in results:
                 b = self._uplink_bytes(r_i[4], r_i[2], density)
@@ -493,8 +510,6 @@ class FLServer:
         # round trip (downlink + discarded uplink) was wasted
         results.sort(key=lambda r: r[0])
         kept = results[:k_target]
-        self.registry.inc("fl.clients_straggled",
-                          len(results) - len(kept))
         for r_i in results[k_target:]:
             b = self._downlink_bytes(r_i[4]) \
                 + self._uplink_bytes(r_i[4], density=density)
@@ -514,10 +529,10 @@ class FLServer:
         weights = jnp.asarray([r[1] for r in kept], jnp.float32)
         # (4) aggregation strategy; packed inputs lower onto the fused
         # dequant+reduce kernel, per rank bucket when the cohort is mixed
-        with self.tracer.span("fl/aggregate", track="fl/round",
-                              round=rnd, n_agg=len(kept)):
+        with self._span("fl/aggregate", round=rnd, n_agg=len(kept)):
             self.global_train = self.aggregator.aggregate(
                 [r[2] for r in kept], weights)
+            self._settle(self.global_train)
         self.round += 1
 
         self._tcc_cum += down_bytes + up_bytes

@@ -132,6 +132,7 @@ def dequant_agg_rows_pallas(packed: Array, scale: Array, zp: Array,
         out_specs=pl.BlockSpec((block_c, n), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, n), jnp.float32),
         interpret=interpret,
+        name="dequant_agg_rows",
     )(jax.lax.bitcast_convert_type(packed, jnp.int32), scale[..., None],
       zp[..., None], weights[:, None, None])
     # column i*Nw + j holds level i of word j: back to level order

@@ -135,6 +135,7 @@ def quant_pack_pallas(x: Array, bits: int, *,
             jax.ShapeDtypeStruct((c, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quant_pack_rows",
     )(x, nv, jnp.asarray(_pack_selectors(bits), jnp.bfloat16))
     packed = jax.lax.bitcast_convert_type(packed, jnp.uint32)
     return packed, scale[:, 0], zp[:, 0]

@@ -4,7 +4,8 @@
     registry (process-global default, disabled until opted in, or an
     injected instance);
   * :mod:`repro.obs.trace` — span tracer on wall OR virtual clocks,
-    Chrome-trace JSON + JSONL export;
+    Chrome-trace JSON + JSONL export; wall-clock spans also land in any
+    ``jax.profiler`` trace;
   * :mod:`repro.obs.compile` — the ONE ``jax.monitoring``
     backend-compile listener: measurement context, enforcing watchdog,
     pytest fixture;

@@ -18,6 +18,12 @@ events whose begin/end the caller already knows in virtual time go
 through :meth:`Tracer.event` with explicit ``ts``/``dur`` — e.g. one
 dispatch->arrival span per in-flight client update.
 
+PROFILER. A span of an enabled tracer on the wall clock is also entered
+as a ``jax.profiler.TraceAnnotation`` of the same name (no args), so that
+it lands on the host plane of any ``jax.profiler`` trace taken around it,
+on the device trace's clock. Spans on a virtual clock annotate nothing:
+their times are not the profiler's.
+
 Like the metrics registry, a disabled tracer records nothing and costs
 one attribute check per call; ``default_tracer()`` is the process-global
 instance (disabled until someone opts in) and engines take
@@ -29,6 +35,8 @@ import contextlib
 import json
 import time
 from typing import Any, Callable, Iterator, Optional
+
+import jax
 
 
 class Tracer:
@@ -56,13 +64,17 @@ class Tracer:
     def span(self, name: str, track: str = "main",
              **args) -> Iterator[None]:
         """``with tracer.span("fl/aggregate", rank=8): ...`` — a
-        complete event from entry to exit on this tracer's clock."""
+        complete event from entry to exit on this tracer's clock, and a
+        profiler annotation when that clock is the wall clock."""
         if not self.enabled:
             yield
             return
+        annotation = jax.profiler.TraceAnnotation(name) \
+            if self.clock is time.perf_counter else contextlib.nullcontext()
         t0 = self.clock()
         try:
-            yield
+            with annotation:
+                yield
         finally:
             self.event(name, ts=t0, dur=self.clock() - t0, track=track,
                        **args)

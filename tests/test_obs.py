@@ -17,6 +17,7 @@ The acceptance contract:
     with obs enabled produce a loadable Chrome trace and a metrics dump
     covering wire bytes, staleness, cache hit rate and compile counts.
 """
+import glob
 import json
 
 import jax
@@ -369,7 +370,7 @@ def test_end_to_end_trace_and_metrics_dump(tmp_path):
     misses = reg.counter_value("serve.cache.misses")
     assert hits + misses > 0 and misses > 0   # cold cache missed first
     assert reg.counter_value("jax.backend_compiles") > 0
-    assert reg.counter_value("fl.rounds") == 1
+    assert [r["round"] for r in srv.history] == [1]
     assert reg.counter_value("fl.flushes") >= 1
     dump_path = tmp_path / "metrics.json"
     reg.dump_json(str(dump_path))
@@ -402,3 +403,95 @@ def test_disabled_obs_records_nothing_through_engines():
     assert obsm.default_registry().dump() == {
         "counters": {}, "gauges": {}, "histograms": {}}
     assert obst.default_tracer().events == []
+
+
+# ---------------------------------------------------------------------------
+# the sync round's spans, and their profiler annotations
+# ---------------------------------------------------------------------------
+
+ROUND_PHASES = ("fl/broadcast", "fl/client_train", "fl/pack", "fl/uplink",
+                "fl/aggregate")
+# span -> the span it lies in
+ROUND_PARENT = {**{p: "fl/round" for p in ROUND_PHASES},
+                "fl/stage_batches": "fl/broadcast",
+                "fl/h2d": "fl/broadcast",
+                "fl/train_wait": "fl/client_train",
+                "fl/slice": "fl/pack", "fl/encode": "fl/pack"}
+
+
+def _traced_sync_server(tracer):
+    data = _lin_data()
+    return FLServer(
+        _lora_model(rank=8), _lora_loss, data,
+        ServerConfig(rounds=1, n_clients=len(data), clients_per_round=3,
+                     seed=0),
+        ClientConfig(local_epochs=1, batch_size=8, lr=0.1),
+        FLoCoRAConfig(rank=8, alpha=8.0, quant_bits=8), tracer=tracer)
+
+
+def _host_span_names(logdir) -> set:
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    data = ProfileData.from_file(path)
+    return {ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_sync_round_spans_nest_inside_the_round():
+    tracer = obst.Tracer()
+    srv = _traced_sync_server(tracer)
+    rec = srv.run_round()
+    evs = [e for e in tracer.events if e["ph"] == "X"]
+    by_name: dict = {}
+    for e in evs:
+        by_name.setdefault(e["name"], []).append(e)
+    assert set(by_name) == {"fl/round", *ROUND_PARENT}, set(by_name)
+    rnd, = by_name["fl/round"]
+    assert rnd["args"] == {"round": 0}
+    # one slice and one encode per cohort client
+    clients = {name: sorted(e["args"]["client"] for e in by_name[name])
+               for name in ("fl/slice", "fl/encode")}
+    assert clients["fl/slice"] == clients["fl/encode"]
+    assert len(set(clients["fl/slice"])) == rec["n_agg"] == 3
+    for e in evs:
+        if e["name"] == "fl/round":
+            continue
+        parents = by_name[ROUND_PARENT[e["name"]]]
+        assert any(p["ts"] <= e["ts"]
+                   and e["ts"] + e["dur"] <= p["ts"] + p["dur"]
+                   for p in parents), e["name"]
+    # the five phases follow one another inside the round
+    phases = sorted((by_name[p][0] for p in ROUND_PHASES),
+                    key=lambda e: e["ts"])
+    assert [e["name"] for e in phases] == list(ROUND_PHASES)
+    for a, b in zip(phases, phases[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+
+
+def test_enabled_tracer_spans_land_in_a_profiler_trace(tmp_path):
+    """No benchmark code: a plain enabled tracer's spans are on the
+    profiler's host plane; a virtual-clock view's are not."""
+    tracer = obst.Tracer()
+    srv = _traced_sync_server(tracer)
+    srv.run_round()                    # compile outside the profile
+    view = tracer.with_clock(lambda: 5.0)
+    with jax.profiler.trace(str(tmp_path)):
+        srv.run_round()
+        with view.span("fl/virtual_only"):
+            pass
+    names = _host_span_names(tmp_path)
+    assert {"fl/round", "fl/pack", "fl/h2d"} <= names, names
+    assert "fl/virtual_only" not in names
+    assert "fl/virtual_only" in {e["name"] for e in tracer.events}
+
+
+def test_disabled_tracer_leaves_no_events_nor_annotations(tmp_path):
+    tracer = obst.Tracer(enabled=False)
+    srv = _traced_sync_server(tracer)
+    srv.run_round()
+    with jax.profiler.trace(str(tmp_path)):
+        srv.run_round()
+    assert tracer.events == []
+    assert not {n for n in _host_span_names(tmp_path)
+                if n.startswith("fl/")}

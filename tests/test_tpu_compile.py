@@ -7,7 +7,9 @@ overflow VMEM. Interpret-mode tests cannot see any of that. Each test
 lowers the wrapper a user calls (``kernels/ops.py``) at the widths the
 FL round and the serving engine use, with the dispatch steered to its
 TPU branch inside the test, and asserts the compiled program holds a
-Mosaic kernel (``tpu_custom_call``).
+Mosaic kernel (``tpu_custom_call``). The codec kernels carry stable
+names (``quant_pack_rows``, ``dequant_agg_rows``) whatever wrapper calls
+them, since the benchmark's roofline readers find them by name.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -77,8 +79,9 @@ def _assert_kernel(fn, *args, **static):
 def test_quant_pack_rows_compiles_at_flat_layout(bits, one_chip,
                                                  tpu_dispatch):
     c, n = FLAT_LAYOUT[bits]
-    _assert_kernel(ops.quant_pack_rows, _spec(one_chip, (c, n)),
-                   _spec(one_chip, (c,), jnp.int32), bits=bits)
+    compiled = _assert_kernel(ops.quant_pack_rows, _spec(one_chip, (c, n)),
+                              _spec(one_chip, (c,), jnp.int32), bits=bits)
+    assert "quant_pack_rows" in compiled.as_text()
 
 
 @pytest.mark.parametrize("k", [5, 1024])
@@ -90,6 +93,7 @@ def test_dequant_agg_rows_compiles(k, one_chip, tpu_dispatch):
         _spec(one_chip, (k, c)), _spec(one_chip, (k, c)),
         _spec(one_chip, (k,)), _spec(one_chip, (c,), jnp.int32), bits=8)
     assert compiled.memory_analysis() is not None
+    assert "dequant_agg_rows" in compiled.as_text()
 
 
 @pytest.mark.parametrize("bits", [8, 4])
@@ -97,11 +101,16 @@ def test_per_leaf_codec_kernels_compile(bits, one_chip, tpu_dispatch):
     """The per-leaf oracle codec's kernels at a ResNet-8 conv leaf's
     channel-first view (32 channels x 3*3*256 taps)."""
     c, n = 32, 3 * 3 * 256
-    _assert_kernel(ops.quant_pack, _spec(one_chip, (c, n)), bits=bits)
+    compiled = _assert_kernel(ops.quant_pack, _spec(one_chip, (c, n)),
+                              bits=bits)
+    # the kernel's own name, not its caller's
+    assert "quant_pack_rows" in compiled.as_text()
     nw = -(-n // ops.lane_levels(bits)) * ops.lane_levels(bits) * bits // 32
-    _assert_kernel(ops.dequant_agg, _spec(one_chip, (5, c, nw), jnp.uint32),
-                   _spec(one_chip, (5, c)), _spec(one_chip, (5, c)),
-                   _spec(one_chip, (5,)), bits=bits)
+    compiled = _assert_kernel(
+        ops.dequant_agg, _spec(one_chip, (5, c, nw), jnp.uint32),
+        _spec(one_chip, (5, c)), _spec(one_chip, (5, c)),
+        _spec(one_chip, (5,)), bits=bits)
+    assert "dequant_agg_rows" in compiled.as_text()
 
 
 @pytest.mark.parametrize("r", [4, 8])
