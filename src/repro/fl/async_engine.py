@@ -74,7 +74,7 @@ from repro.core.flocora import FLoCoRAConfig
 from repro.core.quant import gaussian_epsilon
 from repro.fl.client import ClientConfig, cohort_steps, natural_steps, \
     make_staggered_cohort_trainer, pad_cohort_batches, pow2_pad, \
-    stack_local_batches
+    stack_local_batches, unstack_cohort
 from repro.fl.server import WireAccounting
 from repro.fl.traces import FleetTrace
 from repro.obs import metrics as obsm
@@ -410,8 +410,7 @@ class AsyncFLServer:
                                        jax.tree.map(jnp.asarray, batches),
                                        jnp.asarray(n_steps))
         losses = np.asarray(losses)
-        for k, rec in enumerate(recs):
-            t_k = jax.tree.map(lambda x: x[k], trained)
+        for k, (rec, t_k) in enumerate(zip(recs, unstack_cohort(trained))):
             # density keys off the DISPATCH version (rec.version), a
             # pure function of checkpointed state — resumed runs emit
             # byte-identical uplinks. DP (when configured) privatizes

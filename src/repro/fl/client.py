@@ -137,6 +137,20 @@ def make_cohort_trainer(loss_fn: Callable, cfg: ClientConfig):
                             in_axes=(None, None, 0, 0)))
 
 
+@jax.jit
+def unstack_cohort(trained: Any) -> list:
+    """A cohort trainer's stacked output -> one tree per client row, in
+    ONE dispatch: tree ``k`` equals ``jax.tree.map(lambda x: x[k],
+    trained)`` bit for bit, which costs three eager dispatches per leaf.
+
+    Every row comes back, the masked rows of a pow2-padded bucket too:
+    callers use the first ``len(cohort)``. The program is keyed by the
+    tree's structure and leaf shapes (leading K included), which the
+    cohort trainer already fixes, so it compiles once per bucket shape."""
+    k = jax.tree.leaves(trained)[0].shape[0]
+    return [jax.tree.map(lambda x: x[i], trained) for i in range(k)]
+
+
 def make_staggered_cohort_trainer(loss_fn: Callable, cfg: ClientConfig):
     """Async cohort engine: like ``make_cohort_trainer`` but ``train0``
     carries a leading K dim — each client starts from its OWN adapter

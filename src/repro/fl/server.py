@@ -49,7 +49,8 @@ from repro.core.flocora import FLoCoRAConfig
 from repro.core.quant import gaussian_epsilon
 from repro.checkpoint import CheckpointManager
 from repro.fl.client import ClientConfig, cohort_steps, \
-    make_cohort_trainer, pad_cohort_batches, pow2_pad, stack_cohort_batches
+    make_cohort_trainer, pad_cohort_batches, pow2_pad, \
+    stack_cohort_batches, unstack_cohort
 from repro.fl.traces import FleetTrace
 from repro.obs import metrics as obsm
 from repro.obs import trace as obst
@@ -482,9 +483,10 @@ class FLServer:
                     self._settle(trained)
             with self._span("fl/pack", round=rnd, rank=r,
                             clients=len(cids)):
-                for k, cid in enumerate(cids):
-                    with self._span("fl/slice", client=cid):
-                        t_k = jax.tree.map(lambda x: x[k], trained)
+                with self._span("fl/slice", round=rnd, rank=r,
+                                clients=len(cids)):
+                    per_client = unstack_cohort(trained)
+                for k, (cid, t_k) in enumerate(zip(cids, per_client)):
                     res = self.aggregator.residual(cid, t_k) \
                         if ef else None
                     # start/dp_key engage only when fcfg.dp is set: the

@@ -419,14 +419,28 @@ ROUND_PARENT = {**{p: "fl/round" for p in ROUND_PHASES},
                 "fl/slice": "fl/pack", "fl/encode": "fl/pack"}
 
 
-def _traced_sync_server(tracer):
+def _traced_sync_server(tracer, clients_per_round=3, **fkw):
     data = _lin_data()
     return FLServer(
         _lora_model(rank=8), _lora_loss, data,
-        ServerConfig(rounds=1, n_clients=len(data), clients_per_round=3,
-                     seed=0),
+        ServerConfig(rounds=1, n_clients=len(data),
+                     clients_per_round=clients_per_round, seed=0),
         ClientConfig(local_epochs=1, batch_size=8, lr=0.1),
-        FLoCoRAConfig(rank=8, alpha=8.0, quant_bits=8), tracer=tracer)
+        FLoCoRAConfig(rank=8, alpha=8.0, quant_bits=8, **fkw),
+        tracer=tracer)
+
+
+def _assert_one_slice_per_bucket(by_name: dict) -> None:
+    """Each ``fl/pack`` (one per rank bucket) holds exactly one
+    ``fl/slice``, whose args name the pack's round, rank and clients."""
+    packs, slices = by_name["fl/pack"], by_name["fl/slice"]
+    assert len(slices) == len(packs)
+    for p in packs:
+        inside = [s for s in slices if p["ts"] <= s["ts"]
+                  and s["ts"] + s["dur"] <= p["ts"] + p["dur"]]
+        assert len(inside) == 1
+        assert inside[0]["args"] == {"round": 0, "rank": p["args"]["rank"],
+                                     "clients": p["args"]["clients"]}
 
 
 def _host_span_names(logdir) -> set:
@@ -449,11 +463,11 @@ def test_sync_round_spans_nest_inside_the_round():
     assert set(by_name) == {"fl/round", *ROUND_PARENT}, set(by_name)
     rnd, = by_name["fl/round"]
     assert rnd["args"] == {"round": 0}
-    # one slice and one encode per cohort client
-    clients = {name: sorted(e["args"]["client"] for e in by_name[name])
-               for name in ("fl/slice", "fl/encode")}
-    assert clients["fl/slice"] == clients["fl/encode"]
-    assert len(set(clients["fl/slice"])) == rec["n_agg"] == 3
+    # one slice per rank bucket, in its pack and for its clients; one
+    # encode per cohort client
+    _assert_one_slice_per_bucket(by_name)
+    encoded = [e["args"]["client"] for e in by_name["fl/encode"]]
+    assert len(set(encoded)) == len(encoded) == rec["n_agg"] == 3
     for e in evs:
         if e["name"] == "fl/round":
             continue
@@ -467,6 +481,26 @@ def test_sync_round_spans_nest_inside_the_round():
     assert [e["name"] for e in phases] == list(ROUND_PHASES)
     for a, b in zip(phases, phases[1:]):
         assert a["ts"] + a["dur"] <= b["ts"]
+
+
+def test_mixed_rank_round_slices_once_per_rank_bucket():
+    """Two rank buckets, one padded to a power of two: one slice each,
+    for the bucket's live clients; one encode per cohort client."""
+    tracer = obst.Tracer()
+    srv = _traced_sync_server(
+        tracer, clients_per_round=5,
+        rank_schedule=RankSchedule.tiered((4, 8), 6))
+    rec = srv.run_round()
+    by_name: dict = {}
+    for e in tracer.events:
+        if e["ph"] == "X":
+            by_name.setdefault(e["name"], []).append(e)
+    assert sorted(p["args"]["rank"] for p in by_name["fl/pack"]) == [4, 8]
+    assert sorted(p["args"]["clients"] for p in by_name["fl/pack"]) \
+        == [2, 3]
+    _assert_one_slice_per_bucket(by_name)
+    encoded = [e["args"]["client"] for e in by_name["fl/encode"]]
+    assert len(set(encoded)) == len(encoded) == rec["n_agg"] == 5
 
 
 def test_enabled_tracer_spans_land_in_a_profiler_trace(tmp_path):
